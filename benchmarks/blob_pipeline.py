@@ -35,6 +35,7 @@ from repro.core.keys import model_meta
 from repro.core.net.server import serve_peer_tcp
 from repro.core.transport import TCPTransport
 from repro.data import MMLUGenerator, WordHashTokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.serving.engine import InferenceEngine
 
@@ -214,6 +215,7 @@ def overlap_drill(engine, gen, lines, out, quick=False):
 
 
 def main():
+    enable_compile_cache()
     quick = "--quick" in sys.argv
     cfg, model, params, engine, gen = build_world()
     meta = model_meta(cfg, "float32")

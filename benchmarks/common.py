@@ -15,6 +15,7 @@ from repro.core import CacheServer, EdgeClient, SimClock, SimNetwork
 from repro.core.perfmodel import PI_5, PI_ZERO_2W, TPU_V5E
 from repro.core.transport import InProcTransport
 from repro.data import MMLUGenerator, WordHashTokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.serving.engine import InferenceEngine
 
@@ -52,6 +53,7 @@ def make_world(setting: str = "low") -> World:
         w.server.__init__(CacheConfig())     # fresh server per bench
         w.clock.t = 0.0
         return w
+    enable_compile_cache()
     full = {"low": "gemma3-270m", "high": "gemma3-1b",
             "tpu": "gemma3-1b"}[setting]
     perf = {"low": PI_ZERO_2W, "high": PI_5, "tpu": TPU_V5E}[setting]

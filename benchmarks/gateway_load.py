@@ -36,6 +36,7 @@ from repro.configs import get_config
 from repro.core import Fabric
 from repro.data import WordHashTokenizer
 from repro.gateway import Gateway, TenantQuota, protocol
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.serving import BatchedEngine, Request, Scheduler
 from repro.workloads import MIXES
@@ -356,6 +357,7 @@ def run_mix(gw, model, params, tok, name: str, n: int, rate: float,
 
 
 def main(quick: bool = False, only_mix: str = ""):
+    enable_compile_cache()
     cfg = get_config("gemma3-270m").reduced()
     model = Model(cfg)
     import jax

@@ -30,6 +30,7 @@ import time
 from benchmarks.common import csv_line, write_bench
 from repro.configs import get_config
 from repro.gateway import Gateway, TenantQuota
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.obs.trace import NULL_TRACER
 from repro.workloads import MIXES
@@ -126,6 +127,7 @@ def check_telemetry(gw: Gateway, wl, out: dict) -> None:
 
 
 def main():
+    enable_compile_cache()
     quick = "--quick" in sys.argv
     n = 8 if quick else 24
     warm = 2 if quick else 4

@@ -25,6 +25,7 @@ from repro.config import CacheConfig
 from repro.configs import get_config
 from repro.core import Fabric
 from repro.gateway import Gateway, TenantQuota
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.workloads import customer_support
 
@@ -46,6 +47,7 @@ def main():
                          "TCP peer fleet")
     ap.add_argument("--requests", type=int, default=8)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config("gemma3-270m").reduced()
     model = Model(cfg)

@@ -1,4 +1,4 @@
-"""qwen3-4b [dense] — qk_norm, GQA. [hf:Qwen/Qwen3-8B family]
+"""qwen3-4b [dense] — qk_norm, GQA. [hf:Qwen/Qwen3-4B]
 
 36L d_model=2560 32H (GQA kv=8) d_ff=9728 vocab=151936.
 """
@@ -18,5 +18,5 @@ CONFIG = ModelConfig(
     qk_norm=True,
     rope_theta=1e6,
     tie_embeddings=True,
-    source="hf:Qwen/Qwen3-8B",
+    source="hf:Qwen/Qwen3-4B",
 )

@@ -159,8 +159,9 @@ def _decompress(blob: bytes) -> bytes:
 def _buffers(arr: np.ndarray) -> memoryview:
     """A zero-copy byte view of ``arr`` for msgpack bin encoding. The
     caller guarantees C-contiguity (ascontiguousarray on slices is the
-    single staging buffer; no additional ``tobytes()`` duplicate)."""
-    return memoryview(arr).cast("B")
+    single staging buffer; no additional ``tobytes()`` duplicate).
+    Viewed through uint8 first: numpy exports no buffer for bfloat16."""
+    return memoryview(arr.reshape(-1).view(np.uint8))
 
 
 # ---------------------------------------------------------------------------

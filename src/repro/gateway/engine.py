@@ -321,8 +321,10 @@ class PrefixFetcher:
                                 self.catalog.register(digest)
                                 self.stats["bytes_up"] += len(blob)
                         self.stats["uploads"] += 1
-                    except Exception:
+                    except Exception as e:
                         self.stats["upload_errors"] += 1
+                        FLIGHT.record("upload.failed", client="gateway",
+                                      error=repr(e))
             finally:
                 self._upq.task_done()
 
@@ -553,8 +555,9 @@ class GatewayEngine:
             self.fetcher.upload(entry[0].segments,
                                 self.engine.slot_cache(slot_i),
                                 logits_row[None])
-        except Exception:
+        except Exception as e:
             self.fetcher.stats["upload_errors"] += 1
+            FLIGHT.record("upload.failed", client="gateway", error=repr(e))
 
     def _publish(self) -> None:
         finished = []

@@ -5,7 +5,7 @@
 
   # terminal 2..N — edge clients working an MMLU stream
   PYTHONPATH=src python -m repro.launch.serve client --port 7077 \
-      --arch gemma3-270m --prompts 10
+      --arch gemma3-270m --prompts 10 [--reduced]
 """
 from __future__ import annotations
 
@@ -20,6 +20,7 @@ from repro.core import CacheServer, EdgeClient
 from repro.core.perfmodel import PI_ZERO_2W
 from repro.core.transport import TCPTransport, serve_tcp
 from repro.data import MMLUGenerator, WordHashTokenizer, MMLU_DOMAINS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.serving.engine import InferenceEngine
 
@@ -39,6 +40,7 @@ def run_server(args):
 
 
 def run_client(args):
+    enable_compile_cache()
     cfg = get_config(args.arch)
     exec_cfg = cfg.reduced() if args.reduced else cfg
     model = Model(exec_cfg)
@@ -70,7 +72,8 @@ def main():
     c.add_argument("--host", default="127.0.0.1")
     c.add_argument("--port", type=int, default=7077)
     c.add_argument("--arch", default="gemma3-270m")
-    c.add_argument("--reduced", action="store_true", default=True)
+    c.add_argument("--reduced", action="store_true",
+                   help="run the 2-layer CPU-sized variant of --arch")
     c.add_argument("--prompts", type=int, default=10)
     c.add_argument("--domains", type=int, default=3)
     c.add_argument("--n-shot", type=int, default=2)

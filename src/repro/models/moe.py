@@ -22,13 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.common import activation, dense_init, opt_barrier
 
-# shard_map moved to the jax namespace (and check_rep became check_vma)
-# after the pinned jax floor; support both spellings.
-if hasattr(jax, "shard_map"):
-    _shard_map = partial(jax.shard_map, check_vma=False)
-else:                                  # pragma: no cover - version dependent
-    from jax.experimental.shard_map import shard_map as _experimental_sm
-    _shard_map = partial(_experimental_sm, check_rep=False)
+_shard_map = partial(jax.shard_map, check_vma=False)
 
 # tokens processed per inner MoE chunk on each shard (bounds transients)
 _TOKEN_CHUNK = 8192

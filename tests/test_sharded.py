@@ -24,9 +24,10 @@ def test_moe_ep_matches_local_with_grads():
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np, dataclasses
         import repro.models.moe as moe
+        from repro.launch.mesh import make_debug_mesh
         moe._TOKEN_CHUNK = 8     # force the chunked path
         from repro.configs import get_config
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_debug_mesh()      # 2 data x 4 model
         for arch in ("granite-moe-3b-a800m", "deepseek-v3-671b"):
             cfg = get_config(arch).reduced()
             cfg = cfg.replace(moe=dataclasses.replace(
@@ -56,9 +57,10 @@ def test_sharded_train_step_matches_single_device():
         from repro.models import Model
         from repro.training import adamw, make_train_step
         from repro.launch import shardings as sh
+        from repro.launch.mesh import make_debug_mesh
 
         cfg = get_config("llama3.2-1b").reduced()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_debug_mesh()      # 2 data x 4 model
         key = jax.random.PRNGKey(0)
         batch = {"tokens": jnp.ones((4, 16), jnp.int32),
                  "targets": jnp.ones((4, 16), jnp.int32)}
@@ -90,9 +92,10 @@ def test_sharded_prefill_decode_matches():
         from repro.configs import get_config
         from repro.models import Model
         from repro.launch import shardings as sh
+        from repro.launch.mesh import make_debug_mesh
 
         cfg = get_config("qwen3-4b").reduced()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_debug_mesh()      # 2 data x 4 model
         key = jax.random.PRNGKey(0)
         toks = jnp.asarray(np.random.default_rng(0).integers(
             3, cfg.vocab, (4, 16)), jnp.int32)
@@ -125,8 +128,9 @@ def test_mini_dryrun_lowers_on_8_devices():
         import dataclasses
         from repro.config import ShapeConfig
         from repro.configs import get_config
+        from repro.launch.mesh import make_debug_mesh
         from repro.launch.specs import build_step
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_debug_mesh()      # 2 data x 4 model
         shape_t = ShapeConfig("t", 64, 8, "train")
         shape_d = ShapeConfig("d", 64, 8, "decode", force_window=32)
         for arch in ("llama3.2-1b", "granite-moe-3b-a800m", "mamba2-780m",

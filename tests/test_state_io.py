@@ -1,11 +1,13 @@
 """State blob (de)serialization — the transferable prompt cache."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from conftest import make_batch, prefill_inputs
 from repro.configs import get_config
 from repro.core import state_io
+from repro.core.chunkfmt import pack_container
 from repro.core.keys import model_meta
 from repro.models import Model
 
@@ -40,6 +42,30 @@ def test_roundtrip_and_resume_equivalence(arch):
                           cache, start_pos=10, resume=True)
     np.testing.assert_allclose(np.asarray(lr), np.asarray(ref_logits),
                                atol=2e-5, rtol=1e-4)
+
+
+def test_bf16_cache_ranges_roundtrip_bit_exact():
+    """A bf16 cache (what a chip serves) goes through the v3 chunked
+    range path and back unchanged, bit for bit."""
+    cfg = get_config("qwen3-4b").reduced()
+    model = Model(cfg, dtype=jnp.bfloat16)
+    params = model.init(jax.random.PRNGKey(0))
+    meta = model_meta(cfg, "bfloat16")
+    batch = make_batch(cfg, B=1, S=16)
+    c = model.init_cache(1, model.cache_len(20))
+    lg, c = model.prefill(params, prefill_inputs(cfg, batch), c)
+    chunks = state_io.extract_state_ranges(c, [10, 16], meta,
+                                           logits=np.asarray(lg))
+    for n in (10, 16):
+        payload = state_io.parse_state(pack_container(chunks[n]), meta)
+        got, n_eff, _ = state_io.restore_state(
+            payload, model.init_cache(1, model.cache_len(20)))
+        assert n_eff == n
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(c)):
+            assert a.dtype == jnp.bfloat16
+            np.testing.assert_array_equal(
+                np.asarray(a)[:, :, :n].view(np.uint16),
+                np.asarray(b)[:, :, :n].view(np.uint16))
 
 
 def test_integrity_rejection():
