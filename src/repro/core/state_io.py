@@ -251,25 +251,40 @@ def _extract_state_ranges(cache, n_effs: Sequence[int], meta: bytes,
     re-extraction, no re-compression. ``logits`` attach to the longest
     range only (the full-prompt blob). Returns ``{n_eff: [header,
     chunk, ...]}``; wrap a list with
-    :func:`~repro.core.chunkfmt.pack_container` to store/ship it."""
+    :func:`~repro.core.chunkfmt.pack_container` to store/ship it.
+    Timed as two phases: ``upload.to_host`` (every leaf pulled to the
+    host, ``nbytes``) and ``upload.encode`` (the rest)."""
     bounds = sorted({int(n) for n in n_effs})
     if not bounds:
         raise ValueError("need at least one range length")
-    n_max = bounds[-1]
     STATS["serialize_passes"] += 1
-    meta_hash = hashlib.blake2b(meta, digest_size=16).digest()
-
     leaves = jax.tree_util.tree_flatten_with_path(cache)[0]
+    with phase("upload.to_host") as sp:
+        host = [(path, np.asarray(leaf)) for path, leaf in leaves]
+        sp.set(nbytes=sum(a.nbytes for _, a in host))
+    with phase("upload.encode"):
+        return _encode_state_ranges(host, bounds, meta, logits, compress,
+                                    level, quantize, codec, chunk_layers)
+
+
+def _encode_state_ranges(leaves, bounds: List[int], meta: bytes,
+                         logits: Optional[np.ndarray], compress: bool,
+                         level: int, quantize: bool, codec: str,
+                         chunk_layers: int) -> Dict[int, List[bytes]]:
+    """The host half of :func:`_extract_state_ranges`: cut, quantize,
+    compress and frame ``leaves`` (``(path, host array)`` pairs)."""
+    n_max = bounds[-1]
+    meta_hash = hashlib.blake2b(meta, digest_size=16).digest()
     # group leaves by segment prefix, preserving tree (= compute) order
     seg_order: List[str] = []
     by_seg: Dict[str, list] = {}
-    for path, leaf in leaves:
+    for path, arr in leaves:
         ps = _path_str(path)
         sk = _seg_key(ps)
         if sk not in by_seg:
             by_seg[sk] = []
             seg_order.append(sk)
-        by_seg[sk].append((ps, _leaf_name(path), np.asarray(leaf)))
+        by_seg[sk].append((ps, _leaf_name(path), arr))
 
     # data chunks in stream order: (segment, layer-group) major so the
     # consumer can run layers [lo:hi) the moment their bands are in,

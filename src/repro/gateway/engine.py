@@ -28,6 +28,7 @@ import threading
 from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.config import CacheConfig
@@ -47,7 +48,8 @@ from repro.obs import REGISTRY, clock as oclock
 from repro.obs.flight import FLIGHT
 from repro.obs.ledger import LEDGER, LEDGER_KEY
 from repro.obs.metrics import DEFAULT_BUCKETS
-from repro.obs.trace import NULL_SPAN, NULL_TRACER, Tracer, current_span
+from repro.obs.trace import (NULL_SPAN, NULL_TRACER, Tracer, current_span,
+                             phase, unwatch_gc, watch_gc)
 from repro.serving.scheduler import Request, Scheduler
 
 
@@ -83,6 +85,8 @@ class GatewayJob:
         # after the response is written); the engine thread parents its
         # resolve/slot spans onto ``span.ctx`` — explicit handoff
         self.span = None
+        # when the HTTP thread put the job in the engine's inbox
+        self.enqueued_t = 0.0
 
     def push(self, event: tuple) -> None:
         try:
@@ -273,13 +277,21 @@ class PrefixFetcher:
         on the caller/engine thread — it is device work) and queue the
         PUTs for the uploader thread. Ranges this gateway already
         shipped are skipped — N identical cold prompts cost one
-        upload, not N."""
+        upload, not N. Returns the bytes queued. Under a span, timed as
+        ``upload.slice`` (the device copy finishing), then
+        ``state.serialize`` > ``upload.to_host`` / ``upload.encode``."""
         keys = [k for k in segments.keys(self.meta,
                                          self.cache_cfg.max_ranges,
                                          self.cache_cfg.range_stride)
                 if k.digest not in self._uploaded]
         if not keys:
             return 0
+        if current_span() is not None:
+            with phase("upload.slice"):
+                # traced only: the device copy of the slot, and the work
+                # it waits on, ends here, so the pull to the host is
+                # timed apart from it
+                cache1 = jax.block_until_ready(cache1)
         n = len(segments.token_ids)
         per_key = {k.digest: self.model.cache_len(k.n_tokens)
                    for k in keys}
@@ -405,9 +417,11 @@ class GatewayEngine:
                      else DEFAULT_BUCKETS))
         self._m_latency = REGISTRY.histogram(
             "gateway_request_seconds", "submit-to-finish per request")
-        self._m_done = REGISTRY.counter(
-            "gateway_requests_finished_total",
-            "requests finished by the engine", ("reason",))
+        self._m_inbox = REGISTRY.histogram(
+            "gateway_inbox_wait_seconds",
+            "inbox hand-off to engine pickup per request",
+            **({"buckets": self._queue_wait_buckets}
+               if self._queue_wait_buckets else {}))
         self.inbox: "queue.Queue[GatewayJob]" = queue.Queue()
         self._live: Dict[int, List] = {}      # req_id -> [job, req, sent]
         self._stop = threading.Event()
@@ -417,6 +431,7 @@ class GatewayEngine:
         self.sched: Optional[Scheduler] = None
         self._thread: Optional[threading.Thread] = None
         self._t0 = 0.0
+        self._n_steps = 0
 
     # ------------------------------------------------------------------
     def start(self, timeout_s: float = 120.0) -> "GatewayEngine":
@@ -446,6 +461,7 @@ class GatewayEngine:
     def submit(self, job: GatewayJob) -> None:
         if not self.alive:
             raise GatewayClosed("gateway engine is not running")
+        job.enqueued_t = oclock.monotonic()
         self.inbox.put(job)
 
     # ------------------------------------------------------------------
@@ -470,21 +486,52 @@ class GatewayEngine:
             return
         self.ready.set()
         self._t0 = oclock.monotonic()
-        while not self._stop.is_set():
-            drained = self._drain_inbox()
-            if self.sched.has_work:
-                try:
-                    self.sched.step()
-                except Exception as e:        # a broken step fails every
-                    self._fail_all(repr(e))   # live request, not the
-                    continue                  # whole gateway
-                self._publish()
-            elif not drained:
-                try:
-                    self._start_job(self.inbox.get(timeout=0.05))
-                except queue.Empty:
-                    continue
+        if self.tracer.enabled:
+            watch_gc(self.tracer)
+        try:
+            while not self._stop.is_set():
+                drained = self._drain_inbox()
+                if self.sched.has_work:
+                    self._step()
+                elif not drained:
+                    # one span per stretch with no work
+                    with self.tracer.engine("eng.wait"):
+                        job = self._next_job()
+                    if job is not None:
+                        self._start_job(job)
+        finally:
+            unwatch_gc(self.tracer)
         self._fail_all("gateway shutting down")
+
+    def _step(self) -> None:
+        """One scheduler iteration and its token publication, as one
+        ``eng.step`` span (a profiler step)."""
+        sched = self.sched
+        traced = self.tracer.enabled
+        if traced:
+            queued = len(sched.queue)
+            busy = sum(not s.free for s in sched.slots)
+        with self.tracer.engine("eng.step",
+                                step_num=self._n_steps) as sp:
+            self._n_steps += 1
+            try:
+                sched.step()
+            except Exception as e:        # a broken step fails every
+                self._fail_all(repr(e))   # live request, not the
+                return                    # whole gateway
+            self._publish()
+            if traced:
+                admitted = queued - len(sched.queue)
+                sp.set(admitted=admitted, active=busy + admitted)
+
+    def _next_job(self) -> Optional[GatewayJob]:
+        """Block until a job arrives (``None`` once stopping)."""
+        while not self._stop.is_set():
+            try:
+                return self.inbox.get(timeout=0.05)
+            except queue.Empty:
+                continue
+        return None
 
     def _drain_inbox(self) -> bool:
         drained = False
@@ -501,6 +548,11 @@ class GatewayEngine:
             segs = job.segments
             n = len(segs.token_ids)
             pctx = getattr(job.span, "ctx", None)
+            waited = max(oclock.monotonic() - job.enqueued_t, 0.0)
+            self._m_inbox.observe(waited)
+            if pctx is not None:
+                self.tracer.add("gw.inbox_wait", waited, parent=pctx,
+                                t0=job.enqueued_t)
             cache1, matched, logits, served = None, 0, None, ""
             if self.fetcher is not None:
                 rs = (self.tracer.start("gw.resolve", parent=pctx,
@@ -511,7 +563,8 @@ class GatewayEngine:
                 # `deadline_s`) scopes the whole resolve — the planner
                 # prunes against it and the peers see the remainder
                 with rs, deadline_scope(job.parsed.deadline_s):
-                    self.fetcher.sync()
+                    with phase("catalog.sync"):
+                        self.fetcher.sync()
                     cache1, matched, logits, served = \
                         self.fetcher.resolve(segs)
                     rs.set(matched=matched, served_by=served)
@@ -545,16 +598,21 @@ class GatewayEngine:
             job.push(("error", repr(e)))
 
     def _on_prefill(self, slot_i: int, req: Request, logits_row) -> None:
-        """Fresh prefill = complete cache miss: publish its ranges."""
+        """Fresh prefill = complete cache miss: publish its ranges.
+        Timed as ``gw.upload`` in the miss's trace."""
         if self.fetcher is None or not self.policy.upload_on_miss:
             return
         entry = self._live.get(req.req_id)
         if entry is None or entry[0].matched:
             return
+        pctx = getattr(entry[0].span, "ctx", None)
+        up = (self.tracer.start("gw.upload", parent=pctx)
+              if pctx is not None else NULL_SPAN)
         try:
-            self.fetcher.upload(entry[0].segments,
-                                self.engine.slot_cache(slot_i),
-                                logits_row[None])
+            with up:
+                up.set(packed_bytes=self.fetcher.upload(
+                    entry[0].segments, self.engine.slot_cache(slot_i),
+                    logits_row[None]))
         except Exception as e:
             self.fetcher.stats["upload_errors"] += 1
             FLIGHT.record("upload.failed", client="gateway", error=repr(e))
@@ -574,8 +632,6 @@ class GatewayEngine:
                 self._m_ttft.observe(req.stats.ttft)
                 self._m_latency.observe(lat)
                 self._commit_decision(job, req, lat)
-                self._m_done.labels(
-                    reason=req.stats.finish_reason).inc()
                 job.push(("done", req.stats.finish_reason,
                           {"matched_tokens": job.matched,
                            "served_by": job.served_by,

@@ -38,14 +38,31 @@ returns compact span *descriptors* (``{"name", "rel_s", "dur_s",
 clock); the client re-anchors them inside its own network span
 (:meth:`Tracer.fold_remote`), splitting the residual RTT evenly, so
 one request yields one tree spanning client and daemon processes.
+
+**Engine spans** are the work no single request owns — a serving
+loop's iterations, its idle waits, a batched decode step, a garbage
+collection. They share the trace id :data:`ENGINE_TRACE` and live in a
+bounded ring of their own (:meth:`Tracer.engine`), so hundreds of
+iterations per second never evict a request's tree from the per-trace
+store; ``tracer.trace("engine")`` returns the ring.
+
+**Two clocks, one span.** A process may install a host-annotation
+factory (:func:`set_annotation`): every span entered as a context
+manager then also opens ``factory(name, attrs)`` on entry and closes it
+on exit, so the same name lands in an external profiler's timeline
+(the serving engine installs ``jax.profiler.TraceAnnotation``). This
+package itself never imports a profiler. Spans recorded after the fact
+(:meth:`Tracer.add`, :meth:`Tracer.fold_remote`) are not annotated.
 """
 from __future__ import annotations
 
+import gc
 import os
 import threading
-from collections import OrderedDict
+import weakref
+from collections import OrderedDict, deque
 from contextlib import contextmanager
-from typing import Dict, List, NamedTuple, Optional, Sequence
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence
 
 from repro.obs import clock
 
@@ -61,6 +78,22 @@ class SpanContext(NamedTuple):
     """The picklable handle another thread/process parents onto."""
     trace_id: str
     span_id: str
+
+
+ENGINE_TRACE = "engine"       # trace id shared by every engine span
+ENGINE_SPANS = 16384          # engine ring capacity: the newest are kept
+
+
+# process-wide host-annotation hook: factory(name, attrs) -> a context
+# manager entered/exited with each context-managed span (None: off)
+_annotate: Optional[Callable] = None
+
+
+def set_annotation(factory: Optional[Callable]) -> None:
+    """Install (or, with ``None``, remove) the process-wide
+    host-annotation factory."""
+    global _annotate
+    _annotate = factory
 
 
 # thread-local ambient state: the tracer+span most recently entered on
@@ -80,7 +113,8 @@ class Span:
     """
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "proc",
-                 "t0", "dur", "attrs", "_tracer", "_prev", "_ended")
+                 "t0", "dur", "attrs", "_tracer", "_prev", "_ended",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  span_id: str, parent_id: str, proc: str, t0: float,
@@ -96,6 +130,7 @@ class Span:
         self._tracer = tracer
         self._prev = None
         self._ended = False
+        self._ann = None
 
     @property
     def ctx(self) -> SpanContext:
@@ -124,11 +159,17 @@ class Span:
         self._prev = (getattr(_ambient, "tracer", None),
                       getattr(_ambient, "span", None))
         _ambient.tracer, _ambient.span = self._tracer, self
+        if _annotate is not None:
+            self._ann = _annotate(self.name, self.attrs)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, etype, exc, tb) -> None:
         _ambient.tracer, _ambient.span = self._prev
         self._prev = None
+        if self._ann is not None:
+            ann, self._ann = self._ann, None
+            ann.__exit__(None, None, None)
         if etype is not None:
             self.attrs.setdefault("error", repr(exc))
         self.end()
@@ -176,7 +217,9 @@ class Tracer:
 
     ``proc`` labels which process/component minted each span (e.g.
     ``"client"``, ``"gateway"``, ``"peer:peer0"``). ``max_traces``
-    bounds memory: oldest complete traces are evicted FIFO.
+    bounds memory: oldest complete traces are evicted FIFO. The engine
+    ring holds the newest :data:`ENGINE_SPANS` engine spans and never
+    competes with request traces.
     """
 
     def __init__(self, proc: str = "", enabled: bool = True,
@@ -188,6 +231,11 @@ class Tracer:
         self._lock = threading.Lock()
         self._spans: "OrderedDict[str, List[dict]]" = OrderedDict()
         self._aliases: "OrderedDict[str, str]" = OrderedDict()
+        self._ring: "deque[dict]" = deque(maxlen=ENGINE_SPANS)
+        # engine spans noted from a gc callback, which may run while
+        # this thread holds ``_lock``: appended here without the lock,
+        # moved into the ring by the next call that takes it
+        self._pending: "deque[dict]" = deque(maxlen=ENGINE_SPANS)
 
     # -- span creation -------------------------------------------------
     def _resolve_parent(self, parent) -> Optional[SpanContext]:
@@ -217,6 +265,16 @@ class Tracer:
         return Span(self, name, trace_id, _hex_id(),
                     ctx.span_id if ctx else "", self.proc,
                     clock.monotonic() if t0 is None else t0, attrs)
+
+    def engine(self, name: str, **attrs):
+        """Open an engine span: a child of the thread's ambient span when
+        that is an engine span of this tracer, else a root of the engine
+        ring."""
+        amb = getattr(_ambient, "span", None)
+        parent = amb if (amb is not None and amb._tracer is self
+                         and amb.trace_id == ENGINE_TRACE) \
+            else SpanContext(ENGINE_TRACE, "")
+        return self.start(name, parent=parent, attrs=attrs)
 
     def add(self, name: str, dur: float, parent=None,
             t0: Optional[float] = None, **attrs):
@@ -254,6 +312,10 @@ class Tracer:
     def _record(self, span: Span) -> None:
         d = span.as_dict()
         with self._lock:
+            if span.trace_id == ENGINE_TRACE:
+                self._flush_pending()
+                self._ring.append(d)
+                return
             spans = self._spans.get(span.trace_id)
             if spans is None:
                 spans = self._spans[span.trace_id] = []
@@ -265,6 +327,18 @@ class Tracer:
             if len(spans) < self.max_spans_per_trace:
                 spans.append(d)
 
+    def _flush_pending(self) -> None:
+        """Move gc-noted spans into the ring (caller holds ``_lock``)."""
+        while self._pending:
+            self._ring.append(self._pending.popleft())
+
+    def _note_gc(self, t0: float, dur: float, attrs: dict) -> None:
+        if self.enabled:
+            self._pending.append({
+                "name": "py.gc", "trace": ENGINE_TRACE, "span": _hex_id(),
+                "parent": "", "proc": self.proc, "t0": t0, "dur": dur,
+                "attrs": attrs})
+
     def alias(self, name: str, trace_id: str) -> None:
         """Register a secondary lookup key (e.g. the gateway request id
         ``cmpl-42``) for a trace."""
@@ -275,8 +349,12 @@ class Tracer:
 
     def trace(self, trace_or_alias: str) -> Optional[List[dict]]:
         """All finished spans of one trace (insertion order), by trace
-        id or alias; ``None`` if unknown/evicted."""
+        id or alias; ``None`` if unknown/evicted. ``"engine"``
+        (:data:`ENGINE_TRACE`) returns the engine ring."""
         with self._lock:
+            if trace_or_alias == ENGINE_TRACE:
+                self._flush_pending()
+                return list(self._ring)
             tid = self._aliases.get(trace_or_alias, trace_or_alias)
             spans = self._spans.get(tid)
             return list(spans) if spans is not None else None
@@ -286,9 +364,12 @@ class Tracer:
             return list(self._spans)
 
     def spans(self) -> List[dict]:
-        """Every stored span across traces (export convenience)."""
+        """Every stored span across traces, then the engine ring (export
+        convenience)."""
         with self._lock:
-            return [d for spans in self._spans.values() for d in spans]
+            self._flush_pending()
+            return [d for spans in self._spans.values()
+                    for d in spans] + list(self._ring)
 
     def rollup(self) -> Dict[str, dict]:
         """Per-span-name aggregate: ``{name: {count, total_s}}`` —
@@ -352,6 +433,58 @@ def phase(name: str, **attrs):
         return
     with tracer.start(name, attrs=attrs) as sp:
         yield sp
+
+
+# ---------------------------------------------------------------------------
+# garbage collections as engine spans
+# ---------------------------------------------------------------------------
+
+GC_MIN_S = 1e-3               # shorter collections are not stored
+_gc_tracers: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_gc_thread = threading.local()
+_gc_lock = threading.Lock()    # watch/unwatch only, never the callback
+
+
+def _on_gc(stage: str, info: dict) -> None:
+    """``gc.callbacks`` hook: each collection opens a host annotation
+    (``py.gc``) on the collecting thread; one of ``GC_MIN_S`` or more is
+    stored in every watching tracer's engine ring."""
+    if stage == "start":
+        ann = _annotate("py.gc", {}) if _annotate is not None else None
+        if ann is not None:
+            ann.__enter__()
+        _gc_thread.open = (clock.monotonic(), ann)
+        return
+    t0, ann = getattr(_gc_thread, "open", (None, None))
+    _gc_thread.open = (None, None)
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    if t0 is None:
+        return
+    dur = clock.monotonic() - t0
+    if dur < GC_MIN_S:
+        return
+    attrs = {"generation": info.get("generation"),
+             "collected": info.get("collected"),
+             "thread": threading.current_thread().name}
+    for tr in list(_gc_tracers):
+        tr._note_gc(t0, dur, dict(attrs))
+
+
+def watch_gc(tracer: Tracer) -> None:
+    """Store the process's garbage collections of ``GC_MIN_S`` or more
+    as ``py.gc`` engine spans in ``tracer`` (held weakly)."""
+    with _gc_lock:
+        _gc_tracers.add(tracer)
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+
+
+def unwatch_gc(tracer: Tracer) -> None:
+    with _gc_lock:
+        _gc_tracers.discard(tracer)
+        if not _gc_tracers and _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
 
 
 # ---------------------------------------------------------------------------

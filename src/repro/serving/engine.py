@@ -16,6 +16,12 @@ slot decodes at its own offset in one fused device step.
 
 All model calls are jitted once per (shape bucket). Prefill inputs are
 padded to power-of-two buckets to bound recompilation.
+
+Importing this module puts the program's spans on the profiler's clock:
+it installs ``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation``
+for a span carrying ``step_num``) as :mod:`repro.obs.trace`'s
+host-annotation factory, so every context-managed span also appears
+under its name in a ``jax.profiler`` trace's host plane.
 """
 from __future__ import annotations
 
@@ -28,7 +34,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import clock as oclock
+from repro.obs import trace as otrace
+from repro.obs.trace import phase
 from repro.serving.sampler import greedy
+
+
+def _annotation(name: str, attrs: dict):
+    step = attrs.get("step_num")
+    if step is not None:
+        return jax.profiler.StepTraceAnnotation(name, step_num=step)
+    return jax.profiler.TraceAnnotation(name)
+
+
+otrace.set_annotation(_annotation)
 
 
 def _bucket(n: int, minimum: int = 16) -> int:
@@ -354,8 +372,10 @@ class BatchedEngine:
         logits, rows = self._prefill_fresh_b(
             self.params, jnp.asarray(toks),
             jnp.asarray(starts), jnp.asarray(lasts))
-        logits = np.asarray(jax.block_until_ready(logits))
-        self._scatter_rows(rows, list(slots), k)
+        with phase("prefill.wait", bucket=bucket):
+            logits = np.asarray(jax.block_until_ready(logits))
+        with phase("prefill.scatter"):
+            self._scatter_rows(rows, list(slots), k)
         for s, n in zip(slots, lens):
             self.pos[s] = n
         return logits[:k]
@@ -376,12 +396,15 @@ class BatchedEngine:
         fn = self._prefill_single(resume)
         logits, nc = fn(self.params, {"tokens": toks}, cache1,
                         start_pos, n - 1)
-        logits = np.asarray(jax.block_until_ready(logits))
+        with phase("prefill.wait", bucket=n):
+            logits = np.asarray(jax.block_until_ready(logits))
         idx = jnp.asarray([slot])
-        self.cache = jax.tree.map(
-            lambda big, row: big.at[:, idx].set(
-                row.astype(big.dtype) if row.dtype != big.dtype else row),
-            self.cache, nc)
+        with phase("prefill.scatter"):
+            self.cache = jax.tree.map(
+                lambda big, row: big.at[:, idx].set(
+                    row.astype(big.dtype) if row.dtype != big.dtype
+                    else row),
+                self.cache, nc)
         self.pos[slot] = start_pos + n
         return logits
 
@@ -400,4 +423,5 @@ class BatchedEngine:
             self.params, self.cache,
             jnp.asarray(np.asarray(tokens, np.int32)), jnp.asarray(pos))
         self.pos = np.where(active, self.pos + 1, self.pos).astype(np.int32)
-        return np.asarray(jax.block_until_ready(logits))
+        with phase("decode.wait"):
+            return np.asarray(jax.block_until_ready(logits))

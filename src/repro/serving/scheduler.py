@@ -71,8 +71,10 @@ class Scheduler:
         self.rng = rng
         # slot-lifecycle spans (queue wait / prefill / decode) are
         # emitted per finished request, parented onto ``Request.trace``
-        # when the submitter provided one; NULL_TRACER makes the whole
-        # path free for untraced sim runs
+        # when the submitter provided one; the batched prefill and
+        # decode calls are engine spans (``eng.prefill``, ``eng.decode``
+        # > ``eng.sample``). NULL_TRACER makes the whole path free for
+        # untraced sim runs
         self.tracer = tracer or NULL_TRACER
         self._m_reqs = REGISTRY.counter(
             "sched_requests_total", "requests finished by reason",
@@ -192,14 +194,18 @@ class Scheduler:
                 # token (mirrors EdgeClient's matched-1 resume)
                 start = min(req.n_prefix, int(np.size(req.tokens)) - 1)
                 suffix = np.asarray(req.tokens, np.int32)[start:]
-                lg = eng.prefill_slot(slot_i, suffix, req.cache1, start)
+                with self.tracer.engine("eng.prefill", rows=1,
+                                        resumed=True):
+                    lg = eng.prefill_slot(slot_i, suffix, req.cache1,
+                                          start)
                 self._set_logits(slot_i, lg[0])
             else:
                 fresh.append(slot_i)
         if fresh:
             rows = [np.asarray(self.slots[i].req.tokens, np.int32)
                     for i in fresh]
-            logits = self.engine.prefill_slots(fresh, rows)
+            with self.tracer.engine("eng.prefill", rows=len(fresh)):
+                logits = self.engine.prefill_slots(fresh, rows)
             for j, slot_i in enumerate(fresh):
                 self._set_logits(slot_i, logits[j])
                 if self.on_prefill is not None:
@@ -225,17 +231,26 @@ class Scheduler:
         active = np.array([not s.free for s in self.slots])
         if not active.any():
             return
-        tokens = np.zeros(self.engine.batch_size, np.int32)
-        for i, s in enumerate(self.slots):
-            if not s.free:
-                tokens[i] = s.req.stats.output_tokens[-1]
-        logits = self.engine.decode_batch(tokens, active)
-        self.n_steps += 1
-        sampled = self._sample(logits)
-        for i, s in enumerate(self.slots):
-            if not s.free:
-                self._set_logits(i, logits[i])
-                self._emit(i, int(sampled[i]))
+        attrs = {}
+        if self.tracer.enabled:
+            # live cache length of each active row once this step's
+            # token is written
+            live = [int(self.engine.pos[i]) + 1
+                    for i in np.flatnonzero(active)]
+            attrs = {"rows": len(live), "live": live}
+        with self.tracer.engine("eng.decode", **attrs):
+            tokens = np.zeros(self.engine.batch_size, np.int32)
+            for i, s in enumerate(self.slots):
+                if not s.free:
+                    tokens[i] = s.req.stats.output_tokens[-1]
+            logits = self.engine.decode_batch(tokens, active)
+            self.n_steps += 1
+            with self.tracer.engine("eng.sample"):
+                sampled = self._sample(logits)
+            for i, s in enumerate(self.slots):
+                if not s.free:
+                    self._set_logits(i, logits[i])
+                    self._emit(i, int(sampled[i]))
 
     # ------------------------------------------------------------------
     def step(self) -> None:

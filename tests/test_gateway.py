@@ -229,6 +229,78 @@ def test_routing_and_introspection(gw):
     assert "gateway_ttft_seconds_bucket" in text
 
 
+def test_miss_trace_holds_inbox_wait_and_upload_under_one_root(gw):
+    """A miss's tree: the inbox wait and the upload (slot copy, pull to
+    the host, encoding) live under its ``gw.request`` root; the engine
+    ring serves the iterations, and the inbox wait is a histogram."""
+    body = {"prompt": "a prompt nobody sent before, traced", "max_tokens": 3}
+    r, data = _post(gw, "/v1/completions", body)
+    assert r.status == 200
+    first = json.loads(data)
+    assert first["cache"]["matched_tokens"] == 0
+    r, data = _get(gw, f"/v1/traces/{first['id']}")
+    assert r.status == 200
+    spans = json.loads(data)["spans"]
+    by_name = {d["name"]: d for d in spans}
+    assert {"gw.inbox_wait", "gw.resolve", "catalog.sync", "gw.upload",
+            "upload.slice", "state.serialize", "upload.to_host",
+            "upload.encode"} <= set(by_name)
+    roots = [d for d in spans if not d["parent"]]
+    assert len(roots) == 1 and roots[0]["name"] == "gw.request"
+    root = roots[0]["span"]
+    assert by_name["gw.inbox_wait"]["parent"] == root
+    assert by_name["gw.upload"]["parent"] == root
+    up = by_name["gw.upload"]["span"]
+    assert by_name["upload.slice"]["parent"] == up
+    assert by_name["state.serialize"]["parent"] == up
+    ser = by_name["state.serialize"]["span"]
+    assert by_name["upload.to_host"]["parent"] == ser
+    assert by_name["upload.to_host"]["attrs"]["nbytes"] > 0
+    assert by_name["gw.upload"]["attrs"]["packed_bytes"] > 0
+    assert by_name["catalog.sync"]["parent"] == \
+        by_name["gw.resolve"]["span"]
+    r, data = _get(gw, "/v1/traces/engine")
+    assert r.status == 200
+    ring = json.loads(data)
+    assert ring["trace_id"] == "engine"
+    names = {d["name"] for d in ring["spans"]}
+    assert {"eng.step", "eng.wait", "eng.prefill", "prefill.wait",
+            "eng.decode", "decode.wait", "eng.sample"} <= names
+    text = _get(gw, "/metrics")[1].decode()
+    assert "gateway_inbox_wait_seconds_bucket" in text
+    assert "gateway_requests_finished_total" not in text
+
+
+def test_untraced_gateway_serves_uploads_and_hits(tiny_setup, tok):
+    """With the tracer off the engine thread times nothing, yet a miss
+    still answers the reference's tokens and uploads its prefix, which
+    the next identical prompt then hits."""
+    from repro.obs.trace import NULL_TRACER
+    cfg, model, params = tiny_setup
+    g = Gateway(model, params, fabric=Fabric.local(), batch_size=2,
+                max_len=MAX_LEN, tracer=NULL_TRACER).start()
+    try:
+        prompt = "an untraced prompt that is uploaded on its miss"
+        body = {"prompt": prompt, "max_tokens": 4}
+        r, data = _post(g, "/v1/completions", body)
+        assert r.status == 200
+        first = json.loads(data)
+        assert first["cache"]["matched_tokens"] == 0
+        assert first["choices"][0]["token_ids"] == list(
+            _direct_tokens(model, params, tok, prompt, 4))
+        assert g.engine.fetcher.flush_uploads()
+        assert g.engine.fetcher.stats["uploads"] > 0
+        assert g.engine.fetcher.stats["upload_errors"] == 0
+        r, data = _post(g, "/v1/completions", body)
+        hit = json.loads(data)
+        assert hit["cache"]["matched_tokens"] > 0
+        assert hit["choices"][0]["token_ids"] == \
+            first["choices"][0]["token_ids"]
+        assert g.tracer.spans() == []
+    finally:
+        g.stop()
+
+
 def test_keepalive_pipelines_sequential_requests(gw):
     """Two unary requests down ONE socket: the server must answer both
     (Connection: keep-alive), count the reuse, and link the second
@@ -431,9 +503,17 @@ def test_gateway_trace_spans_client_and_remote_daemon(tiny_setup):
         try:
             body = {"prompt": "trace me across the fleet",
                     "max_tokens": 3}
-            r1, _ = _post(g, "/v1/completions", body)
+            r1, d1 = _post(g, "/v1/completions", body)
             assert r1.status == 200
             g.engine.fetcher.flush_uploads()
+            # the miss: inbox wait and upload under its one root
+            miss = json.loads(_get(g, "/v1/traces/"
+                                   f"{json.loads(d1)['id']}")[1])["spans"]
+            assert {"gw.inbox_wait", "gw.upload", "upload.slice",
+                    "upload.to_host", "upload.encode"} <= \
+                {d["name"] for d in miss}
+            assert [d["name"] for d in miss if not d["parent"]] == \
+                ["gw.request"]
             # retry until the uploaded prefix is visible through the
             # gossiped catalog and a daemon actually serves the hit
             deadline = time.monotonic() + 60
@@ -454,8 +534,9 @@ def test_gateway_trace_spans_client_and_remote_daemon(tiny_setup):
             spans = doc["spans"]
             names = {d["name"] for d in spans}
             assert "gw.request" in names and "gw.resolve" in names
-            assert {"slot.queue_wait", "slot.prefill",
-                    "slot.decode"} <= names
+            assert {"gw.inbox_wait", "catalog.sync", "slot.queue_wait",
+                    "slot.prefill", "slot.decode"} <= names
+            assert "gw.upload" not in names          # a hit uploads nothing
             # cross-process: folded spans minted by the daemon process
             remote = [d for d in spans
                       if str(d["proc"]).startswith("peer:")]

@@ -1,7 +1,9 @@
 """Observability: span trees (nesting, cross-thread handoff, remote
-folding), Prometheus exposition, Perfetto export, trace-context wire
-interop (v2 <-> v3), the failure flight recorder, and the JAX-free
-import graph of ``repro.obs`` + the peer daemon."""
+folding), the engine ring, the host-annotation hook, Prometheus
+exposition, Perfetto export, trace-context wire interop (v2 <-> v3),
+the failure flight recorder, and the JAX-free import graph of
+``repro.obs`` + the peer daemon."""
+import gc
 import json
 import os
 import subprocess
@@ -20,8 +22,9 @@ from repro.obs import clock as oclock
 from repro.obs.export import perfetto_trace, span_tree, write_perfetto
 from repro.obs.flight import CHUNK_ERROR, FLIGHT, FlightRecorder
 from repro.obs.metrics import MetricsRegistry, merge_snapshots
-from repro.obs.trace import (NULL_SPAN, NULL_TRACER, SPANS_KEY,
-                             TRACE_KEY, SpanContext, Tracer,
+from repro.obs import trace as otrace
+from repro.obs.trace import (ENGINE_TRACE, NULL_SPAN, NULL_TRACER,
+                             SPANS_KEY, TRACE_KEY, SpanContext, Tracer,
                              current_span, extract_trace, inject_trace,
                              phase)
 from repro.serving.engine import InferenceEngine
@@ -90,6 +93,88 @@ def test_tracer_alias_and_bounded_store():
     assert tr.trace(ids[0]) is None          # FIFO-evicted
     assert tr.trace("cmpl-0") is None        # alias evicted with it
     assert tr.trace("cmpl-2")[0]["name"] == "r2"
+
+
+def test_engine_ring_is_bounded_keeps_newest_and_spares_requests(
+        monkeypatch):
+    monkeypatch.setattr(otrace, "ENGINE_SPANS", 6)
+    tr = Tracer(proc="t", max_traces=2)
+    with tr.start("gw.request") as req:
+        pass
+    for i in range(10):
+        with tr.engine("eng.step", step_num=i) as step:
+            with tr.engine("eng.decode"):
+                with phase("decode.wait") as w:
+                    assert w.trace_id == ENGINE_TRACE
+        assert step.parent_id == ""          # a root of the ring
+    ring = tr.trace("engine")
+    assert len(ring) == 6                    # bounded
+    steps = [d for d in ring if d["name"] == "eng.step"]
+    assert [d["attrs"]["step_num"] for d in steps] == [8, 9]   # newest
+    decode = {d["span"]: d for d in ring if d["name"] == "eng.decode"}
+    assert all(d["parent"] in decode for d in ring
+               if d["name"] == "decode.wait")
+    # thirty engine roots evicted no request trace
+    assert tr.trace(req.trace_id)[0]["name"] == "gw.request"
+    assert tr.trace_ids() == [req.trace_id]
+    assert tr.spans() == tr.trace(req.trace_id) + ring
+
+
+def test_engine_span_inside_a_request_span_roots_in_the_ring():
+    tr = Tracer(proc="t")
+    with tr.start("gw.request") as req:
+        with tr.engine("eng.wait") as w:
+            pass
+    assert w.trace_id == ENGINE_TRACE and w.parent_id == ""
+    assert [d["name"] for d in tr.trace(req.trace_id)] == ["gw.request"]
+    assert Tracer(enabled=False).engine("eng.step") is NULL_SPAN
+
+
+def test_annotation_hook_wraps_entered_spans_only():
+    seen = []
+
+    class Ann:
+        def __init__(self, name, attrs):
+            self.name, self.attrs = name, dict(attrs)
+
+        def __enter__(self):
+            seen.append(("enter", self.name, self.attrs))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name, self.attrs))
+
+    was = otrace._annotate
+    otrace.set_annotation(Ann)
+    try:
+        tr = Tracer(proc="t")
+        with tr.engine("eng.step", step_num=3):
+            with phase("decode.wait"):
+                pass
+            tr.add("after.the.fact", 0.001)
+        NULL_TRACER.add("x", 0.1)
+        with NULL_TRACER.start("y"):
+            pass
+    finally:
+        otrace.set_annotation(was)
+    assert seen == [("enter", "eng.step", {"step_num": 3}),
+                    ("enter", "decode.wait", {}),
+                    ("exit", "decode.wait", {}),
+                    ("exit", "eng.step", {"step_num": 3})]
+
+
+def test_gc_collections_become_engine_spans(monkeypatch):
+    monkeypatch.setattr(otrace, "GC_MIN_S", 0.0)
+    tr = Tracer(proc="t")
+    otrace.watch_gc(tr)
+    try:
+        gc.collect()
+    finally:
+        otrace.unwatch_gc(tr)
+    assert otrace._on_gc not in gc.callbacks
+    spans = [d for d in tr.trace("engine") if d["name"] == "py.gc"]
+    assert spans and spans[-1]["attrs"]["generation"] == 2
+    assert spans[-1]["attrs"]["thread"] == threading.current_thread().name
+    assert spans[-1]["parent"] == "" and spans[-1]["dur"] >= 0.0
 
 
 def test_fold_remote_centers_server_window():
@@ -409,6 +494,20 @@ def test_import_graph_is_jax_free_static():
     from repro.analysis.core import load_tree
     findings = run_rules(load_tree(SRC), rules=("R1",))
     assert not findings, "\n".join(f.render() for f in findings)
+
+
+def test_annotation_hook_is_installed_from_the_serving_side():
+    """``repro.obs`` imports no profiler and installs no annotation;
+    importing the serving engine installs JAX's."""
+    code = ("import sys; from repro.obs import trace;"
+            "assert trace._annotate is None;"
+            "assert 'jax' not in sys.modules, 'jax imported by repro.obs';"
+            "import repro.serving.engine as e;"
+            "assert trace._annotate is e._annotation")
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr + proc.stdout
 
 
 def test_import_graph_is_jax_free_runtime_smoke():
