@@ -608,11 +608,16 @@ class GatewayEngine:
         pctx = getattr(entry[0].span, "ctx", None)
         up = (self.tracer.start("gw.upload", parent=pctx)
               if pctx is not None else NULL_SPAN)
+        # the longest range any key of this prompt can have: the slot is
+        # pulled to the host only that far (its bucket)
+        n_positions = self.model.cache_len(len(entry[0].segments.token_ids))
         try:
             with up:
-                up.set(packed_bytes=self.fetcher.upload(
-                    entry[0].segments, self.engine.slot_cache(slot_i),
-                    logits_row[None]))
+                cache1 = self.engine.slot_cache(slot_i, n_positions)
+                up.set(pulled_positions=self.engine.pull_positions(
+                           n_positions),
+                       packed_bytes=self.fetcher.upload(
+                           entry[0].segments, cache1, logits_row[None]))
         except Exception as e:
             self.fetcher.stats["upload_errors"] += 1
             FLIGHT.record("upload.failed", client="gateway", error=repr(e))

@@ -32,7 +32,9 @@ from typing import Any, Callable, Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
+from repro.core.state_io import SEQ_LEAVES
 from repro.obs import clock as oclock
 from repro.obs import trace as otrace
 from repro.obs.trace import phase
@@ -251,6 +253,18 @@ class InferenceEngine:
 # batched engine (continuous batching substrate)
 # ---------------------------------------------------------------------------
 
+@partial(jax.jit, static_argnums=2)
+def _cut_slot(cache, slot, n_pull: int):
+    """Row ``slot`` of every ``[L, B, ...]`` leaf; sequence leaves cut on
+    axis 2 to their first ``n_pull`` positions. The slot is traced and
+    the cut static: one compile per pull bucket, not per slot."""
+    def cut(path, a):
+        if getattr(path[-1], "key", None) in SEQ_LEAVES:
+            a = a[:, :, :min(n_pull, a.shape[2])]
+        return lax.dynamic_slice_in_dim(a, slot, 1, axis=1)
+    return jax.tree_util.tree_map_with_path(cut, cache)
+
+
 class BatchedEngine:
     """Fixed pool of ``batch_size`` cache slots with per-slot positions.
 
@@ -328,9 +342,25 @@ class BatchedEngine:
             lambda big, new: big.at[:, idx].set(new[:, :n_rows]),
             self.cache, rows)
 
-    def slot_cache(self, slot: int):
-        """A B=1 view of one slot's cache (for state_io extraction)."""
-        return jax.tree.map(lambda a: a[:, slot:slot + 1], self.cache)
+    def pull_positions(self, n_positions: int) -> int:
+        """Sequence positions :meth:`slot_cache` keeps for an upload of
+        ``n_positions`` cache positions: their power-of-two bucket, at
+        least 64, capped at the slot's capacity. Below 64 positions
+        (9.4 MB of qwen3-4b's bf16 K/V) the pull to the host costs its
+        fixed overhead, not its bytes; the floor also gives every short
+        prompt one pull shape, so a server warmed with one short request
+        compiles no new cut for later ones."""
+        return min(_bucket(n_positions, minimum=64),
+                   self.model.cache_len(self.max_len))
+
+    def slot_cache(self, slot: int, n_positions: int):
+        """The B=1 cache of ``slot`` for state_io extraction, cut on the
+        device to what an upload of ``n_positions`` cache positions
+        needs: sequence leaves (``SEQ_LEAVES``) to
+        :meth:`pull_positions` positions, or their capacity if smaller
+        (a ring that wrapped ships whole), every other leaf whole."""
+        return _cut_slot(self.cache, jnp.int32(slot),
+                         self.pull_positions(n_positions))
 
     def free_slot(self, slot: int) -> None:
         self.pos[slot] = 0

@@ -6,6 +6,7 @@ import json
 import time
 import warnings
 
+import jax
 import numpy as np
 import pytest
 
@@ -255,7 +256,13 @@ def test_miss_trace_holds_inbox_wait_and_upload_under_one_root(gw):
     assert by_name["state.serialize"]["parent"] == up
     ser = by_name["state.serialize"]["span"]
     assert by_name["upload.to_host"]["parent"] == ser
-    assert by_name["upload.to_host"]["attrs"]["nbytes"] > 0
+    # the slot crosses to the host only as far as the prompt's bucket:
+    # 64 of its 128 positions
+    assert by_name["gw.upload"]["attrs"]["pulled_positions"] == 64
+    leaves = jax.tree.leaves(gw.engine.engine.cache)
+    assert all(a.shape[2] == MAX_LEN for a in leaves)
+    assert by_name["upload.to_host"]["attrs"]["nbytes"] == sum(
+        a.nbytes // a.shape[1] // MAX_LEN * 64 for a in leaves)
     assert by_name["gw.upload"]["attrs"]["packed_bytes"] > 0
     assert by_name["catalog.sync"]["parent"] == \
         by_name["gw.resolve"]["span"]
