@@ -1,7 +1,26 @@
 """Dense GQA decoder (Qwen3, Llama/Yi): weights, plain reference, work.
 
-Everything here is the benchmark's own and imports nothing of the
-program:
+A model family is one file ``bench/families/<family>.py``; a
+configuration file names it under ``"family"``, and the harness loads
+it by that name (``harness.load_family``) and calls nothing else of it.
+Its interface, which this file is the first to fill:
+
+* ``Shape(spec)``: the sizes the configuration file ``spec`` states;
+  at least ``vocab`` and ``n_layers``. It is what a per-layer metric
+  reads as ``Records.shape``.
+* ``program_config(spec, base)``: the program's ``ModelConfig`` for
+  ``spec``, from the registry's ``base``.
+* ``make_params_jit(shape, key)``: the served weights, from the raw key
+  ``common.base_key(seed)``, in the program's layout.
+* ``Reference(shape, fp8=False)``: ``.logits_at(seed, tokens, pos,
+  row_cap)`` and, after it, ``.first_layer_s``; ``fp8=True`` is the
+  control.
+* ``decode_work(shape, live)``: ``(flops, bytes)`` of one decode step.
+* ``weight_params(shape)``: the weights' parameter count.
+
+What every family shares is in ``bench/lib/common.py``. Everything here
+is the benchmark's own and imports nothing of the program (but
+``program_config`` fills the program's config object it is handed):
 
 * ``make_params`` draws the served weights from the seed, on the
   device, in one jitted call, in bf16 and in the layout the program's
@@ -28,6 +47,8 @@ from typing import Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench.lib.common import base_key, mm, rms
 
 LAYER_LEAVES = ("ln1", "wq", "wk", "wv", "wo", "q_norm", "k_norm", "ln2",
                 "w_up", "w_gate", "w_down")
@@ -69,11 +90,14 @@ class Shape:
                 if self.qk_norm or n not in ("q_norm", "k_norm")]
 
 
-def base_key(seed: int) -> np.ndarray:
-    """A raw threefry key for any seed up to 63 bits."""
-    seed = int(seed)
-    return np.asarray([(seed >> 32) & 0xFFFFFFFF, seed & 0xFFFFFFFF],
-                      np.uint32)
+def program_config(spec: dict, base):
+    """The program's ``ModelConfig``: the registry's ``base`` with the
+    sizes ``spec`` states."""
+    sh = Shape(spec)
+    return base.replace(
+        n_layers=sh.n_layers, d_model=sh.d, n_heads=sh.h,
+        n_kv_heads=sh.kv, head_dim=sh.dh, d_ff=sh.ff, vocab=sh.vocab,
+        tie_embeddings=sh.tied, rope_theta=sh.theta, qk_norm=sh.qk_norm)
 
 
 def layer_leaf(key, layer, name: str, shape: Shape):
@@ -133,28 +157,6 @@ def make_params(shape: Shape, key) -> Dict:
 # the plain reference
 # ---------------------------------------------------------------------------
 
-HIGHEST = jax.lax.Precision.HIGHEST
-F8 = jnp.float8_e4m3fn
-F8_MAX = 448.0
-
-
-def _q8(x):
-    """Round to float8_e4m3fn with a per-tensor scale; back to f32."""
-    s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / F8_MAX
-    return (x / s).astype(F8).astype(jnp.float32) * s
-
-
-def _mm(spec: str, a, b, fp8: bool):
-    if fp8:
-        a, b = _q8(a), _q8(b)
-    return jnp.einsum(spec, a, b, precision=HIGHEST)
-
-
-def _rms(x, gain_offset, eps):
-    x = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
-    return x * (1.0 + gain_offset)
-
-
 def _rope(x, theta):
     """x [S, heads, dh]; rotate-half RoPE at positions 0..S-1."""
     half = x.shape[-1] // 2
@@ -185,46 +187,46 @@ class Reference:
     def _attn_one(self, w, h):
         """h [S, d] of one sequence -> attention output [S, d]."""
         s, f8 = self.s, self.fp8
-        q = _mm("sd,dhk->shk", h, w["wq"], f8)
-        k = _mm("sd,dhk->shk", h, w["wk"], f8)
-        v = _mm("sd,dhk->shk", h, w["wv"], f8)
+        q = mm("sd,dhk->shk", h, w["wq"], f8)
+        k = mm("sd,dhk->shk", h, w["wk"], f8)
+        v = mm("sd,dhk->shk", h, w["wv"], f8)
         if s.qk_norm:
-            q = _rms(q, w["q_norm"], s.eps)
-            k = _rms(k, w["k_norm"], s.eps)
+            q = rms(q, w["q_norm"], s.eps)
+            k = rms(k, w["k_norm"], s.eps)
         q, k = _rope(q, s.theta), _rope(k, s.theta)
         rep = s.h // s.kv
         k = jnp.repeat(k, rep, axis=1)          # head i uses kv head i//rep
         v = jnp.repeat(v, rep, axis=1)
-        sc = _mm("qhk,shk->hqs", q, k, f8) / np.sqrt(s.dh)
+        sc = mm("qhk,shk->hqs", q, k, f8) / np.sqrt(s.dh)
         n = h.shape[0]
         causal = jnp.arange(n)[:, None] >= jnp.arange(n)[None, :]
         sc = jnp.where(causal[None], sc, -jnp.inf)
         pr = jax.nn.softmax(sc, axis=-1)
-        o = _mm("hqs,shk->qhk", pr, v, f8)
-        return _mm("qhk,hkd->qd", o, w["wo"], f8)
+        o = mm("hqs,shk->qhk", pr, v, f8)
+        return mm("qhk,hkd->qd", o, w["wo"], f8)
 
     def _layer_fn(self, key, layer, x):
         """x [B, S, d] -> x after decoder layer ``layer``."""
         s, f8 = self.s, self.fp8
         w = {n: self._w(key, layer, n) for n in s.layer_leaves()}
-        h = _rms(x, w["ln1"], s.eps)
+        h = rms(x, w["ln1"], s.eps)
         x = x + jax.lax.map(lambda hb: self._attn_one(w, hb), h)
-        h = _rms(x, w["ln2"], s.eps)
-        g = _mm("bsd,df->bsf", h, w["w_gate"], f8)
-        u = _mm("bsd,df->bsf", h, w["w_up"], f8)
-        return x + _mm("bsf,fd->bsd", jax.nn.silu(g) * u, w["w_down"], f8)
+        h = rms(x, w["ln2"], s.eps)
+        g = mm("bsd,df->bsf", h, w["w_gate"], f8)
+        u = mm("bsd,df->bsf", h, w["w_up"], f8)
+        return x + mm("bsf,fd->bsd", jax.nn.silu(g) * u, w["w_down"], f8)
 
     def _head_fn(self, key, x, b, p):
         """Logits [N, vocab] at the positions (b[i], p[i]) of the final
         hidden states x [B, S, d]."""
         s = self.s
         g = final_norm(key, s).astype(jnp.float32)
-        h = _rms(x[b, p], g, s.eps)
+        h = rms(x[b, p], g, s.eps)
         if s.tied:
-            return _mm("nd,vd->nv", h,
-                       embed_rows(key, s).astype(jnp.float32), self.fp8)
-        return _mm("nd,dv->nv", h, head_cols(key, s).astype(jnp.float32),
-                   self.fp8)
+            return mm("nd,vd->nv", h,
+                      embed_rows(key, s).astype(jnp.float32), self.fp8)
+        return mm("nd,dv->nv", h, head_cols(key, s).astype(jnp.float32),
+                  self.fp8)
 
     def logits_at(self, seed: int, tokens: np.ndarray,
                   rows: Sequence[Tuple[int, int]],
@@ -249,13 +251,6 @@ class Reference:
         out = np.asarray(self._head(key, x, jnp.asarray(b), jnp.asarray(p)))
         del x
         return out[:n]
-
-
-def served_gaps(logits: np.ndarray, served: Sequence[int]) -> np.ndarray:
-    """How far each served token's reference logit lies below the
-    reference's best at that position."""
-    idx = np.arange(len(served))
-    return logits.max(axis=-1) - logits[idx, np.asarray(served)]
 
 
 # ---------------------------------------------------------------------------
@@ -287,19 +282,6 @@ def decode_work(shape: Shape, live: Sequence[int]) -> Tuple[float, float]:
               + (0 if s.tied else rows * s.d * 2)          # embedding rows
               + rows * s.vocab * 4)                        # logits out
     return flops, nbytes
-
-
-def check_layout(params, expected) -> List[str]:
-    """Differences between the weights made here and the program's own
-    parameter shapes (``jax.eval_shape`` of its init)."""
-    got = jax.tree_util.tree_flatten_with_path(params)[0]
-    want = jax.tree_util.tree_flatten_with_path(expected)[0]
-    g = {jax.tree_util.keystr(p): (tuple(a.shape), str(a.dtype))
-         for p, a in got}
-    w = {jax.tree_util.keystr(p): (tuple(a.shape), str(a.dtype))
-         for p, a in want}
-    return [f"{k}: made {g.get(k)} program {w.get(k)}"
-            for k in sorted(set(g) | set(w)) if g.get(k) != w.get(k)]
 
 
 make_params_jit = jax.jit(make_params, static_argnums=0)

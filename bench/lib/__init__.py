@@ -1,1 +1,2 @@
-"""The benchmark's yardstick: traffic, client, reference, trace reduction."""
+"""The benchmark's yardstick: traffic, client, what the model families
+share, trace reduction."""

@@ -1,16 +1,19 @@
 """One cell, one run: build the served path, warm it, drive the window,
 check the served tokens against the reference, report.
 
-Everything cell-specific is read from data files found by name:
+Everything cell-specific is read from files found by name:
 ``BENCHMARK.json`` names the cell's configuration and traffic;
-``bench/configs/<config>.json`` holds the sizes,
-``bench/traffic/<traffic>.json`` the mix, ``bench/cells/<cell>.json``
-the offered rate and the correctness limit, ``bench/metrics/<metric>.py``
-one reader per per-layer metric and ``bench/probes/*.py`` the traced
-calls. Nothing here names a configuration, a mix or a metric.
+``bench/configs/<config>.json`` holds the sizes and names the model
+family, ``bench/families/<family>.py`` its weights, reference and work
+counts, ``bench/traffic/<traffic>.json`` the mix,
+``bench/cells/<cell>.json`` the offered rate and the correctness limit,
+``bench/metrics/<metric>.py`` one reader per per-layer metric and
+``bench/probes/*.py`` the traced calls. Nothing here names a
+configuration, a family, a mix or a metric.
 """
 from __future__ import annotations
 
+import functools
 import gc
 import importlib.util
 import json
@@ -25,6 +28,7 @@ import numpy as np
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+FAMILIES = BENCH / "families"
 CACHE_DIR = ROOT / ".bench_cache" / "jax"
 
 COMPILES: List[tuple] = []           # (perf_counter, program name)
@@ -64,12 +68,34 @@ def load_json(p: Path) -> dict:
     return json.loads(Path(p).read_text())
 
 
-def load_reader(name: str):
-    f = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", f)
+@functools.cache
+def _load_module(f: Path, name: str):
+    """One module per file and process, so a family's jitted functions
+    and their compiled programs are shared by all who load it."""
+    spec = importlib.util.spec_from_file_location(name, f)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str):
+    return _load_module(BENCH / "metrics" / f"{name}.py",
+                        f"bench_metric_{name}").read
+
+
+def load_family(config: dict, where: str):
+    """The module ``bench/families/<family>.py`` that the configuration
+    names under ``"family"`` (its interface: PERF.md, section 3).
+    ``where`` names the configuration in the error raised when the key
+    or the file is missing."""
+    present = sorted(f.stem for f in FAMILIES.glob("*.py"))
+    name = config.get("family")
+    if name not in present:
+        raise ValueError(
+            f"{where}: " + ("no \"family\" key" if name is None else
+                            f"family {name!r} has no file in {FAMILIES}")
+            + f"; families present: {', '.join(present) or 'none'}")
+    return _load_module(FAMILIES / f"{name}.py", f"bench_family_{name}")
 
 
 class CellSpec:
@@ -82,6 +108,7 @@ class CellSpec:
         self.name = workload
         self.chips = int(w["chips"])
         self.config = load_json(ROOT / file)
+        self.family = load_family(self.config, file)
         self.mix = load_json(BENCH / "traffic" / f"{w['traffic']}.json")
         self.cell = load_json(BENCH / "cells" / f"{workload}.json")
         self.end_to_end = [m for m in bench["end_to_end"]
@@ -96,6 +123,7 @@ class CellSpec:
         self = cls.__new__(cls)
         self.bench, self.name, self.chips = None, name, chips
         self.config, self.mix, self.cell = config, mix, cell
+        self.family = load_family(config, f"the configuration of {name}")
         self.end_to_end, self.per_layer = list(end_to_end), list(per_layer)
         return self
 
@@ -105,12 +133,14 @@ def say(*a) -> None:
 
 
 class Served:
-    """The program under test, built from a config file and a seed."""
+    """The program under test, built from a config file, its family's
+    module and a seed."""
 
-    def __init__(self, config: dict, seed: int, tracer_traces: int = 0):
+    def __init__(self, config: dict, family, seed: int,
+                 tracer_traces: int = 0):
         import jax
         import jax.numpy as jnp
-        from bench.lib import dense
+        from bench.lib.common import base_key, check_layout
         sys.path.insert(0, str(ROOT / "src"))
         from repro.configs import get_config
         from repro.core import Fabric
@@ -121,16 +151,12 @@ class Served:
         self.parts: Dict[str, float] = {}
         t = time.perf_counter()
         self.config = config
-        self.shape = sh = dense.Shape(config)
-        cfg = get_config(config["registry"]).replace(
-            n_layers=sh.n_layers, d_model=sh.d, n_heads=sh.h,
-            n_kv_heads=sh.kv, head_dim=sh.dh, d_ff=sh.ff, vocab=sh.vocab,
-            tie_embeddings=sh.tied, rope_theta=sh.theta,
-            qk_norm=sh.qk_norm)
+        self.shape = family.Shape(config)
+        cfg = family.program_config(config, get_config(config["registry"]))
         self.model = Model(cfg, dtype=jnp.bfloat16)
-        key = jnp.asarray(dense.base_key(seed))
-        params = dense.make_params_jit(sh, key)
-        diffs = dense.check_layout(
+        key = jnp.asarray(base_key(seed))
+        params = family.make_params_jit(self.shape, key)
+        diffs = check_layout(
             params, jax.eval_shape(self.model.init, jax.random.PRNGKey(0)))
         if diffs:
             raise RuntimeError("weights do not fit the program's layout: "
@@ -431,24 +457,24 @@ def judge(gap: float, served: int, failed: int, limit: float):
     return served >= 1 and gap <= limit and failed <= 0, checks
 
 
-def logit_gap(shape, seed: int, toks, rows, fp8: bool = False,
+def logit_gap(family, shape, seed: int, toks, rows, fp8: bool = False,
               row_cap: int = 0):
-    """Widest gap by which a served token's reference logit lies below
-    the reference's best (and, with ``fp8``, the gaps of the tokens the
-    fp8 control ranks first)."""
-    from bench.lib import dense
-    ref = dense.Reference(shape)
+    """Widest gap by which a served token's reference logit (the
+    family's ``Reference``) lies below the reference's best (and, with
+    ``fp8``, the gaps of the tokens the fp8 control ranks first)."""
+    from bench.lib.common import served_gaps
+    ref = family.Reference(shape)
     pos = [(b, p) for b, p, _ in rows]
     lg = ref.logits_at(seed, toks, pos, row_cap)
     say(f"reference: block {list(toks.shape)}, first layer (with its "
         f"compile or cache load) {ref.first_layer_s:.3f} s")
-    gaps = dense.served_gaps(lg, [t for _, _, t in rows])
+    gaps = served_gaps(lg, [t for _, _, t in rows])
     out = {"program": float(gaps.max())}
     if fp8:
-        c = dense.Reference(shape, fp8=True).logits_at(seed, toks, pos,
-                                                       row_cap)
+        c = family.Reference(shape, fp8=True).logits_at(seed, toks, pos,
+                                                        row_cap)
         out["control_fp8"] = float(
-            dense.served_gaps(lg, c.argmax(axis=-1)).max())
+            served_gaps(lg, c.argmax(axis=-1)).max())
     return out
 
 
@@ -492,7 +518,6 @@ def run(spec: CellSpec, seed: int, seconds: float, trace: bool,
     """One run of the cell. With ``control`` the fp8 reference is also
     read on the same served tokens (``checks.control_fp8``); the
     benchmark's own runs never do that."""
-    from bench.lib import dense
     from bench.lib import traffic as tr
     from bench.lib.probes import Recorder, load_probe_files
     from bench.lib.textproto import chat_ids
@@ -500,7 +525,7 @@ def run(spec: CellSpec, seed: int, seconds: float, trace: bool,
     parts = {"process_and_imports": time.perf_counter() - t_start}
     rate = float(spec.cell["rate_per_s"])
     reqs = tr.schedule(spec.mix, seed, rate, seconds)
-    served = Served(spec.config, seed,
+    served = Served(spec.config, spec.family, seed,
                     tracer_traces=(len(reqs) + 256) if trace else 0)
     stopped = False
     tdir = None
@@ -592,7 +617,8 @@ def run(spec: CellSpec, seed: int, seconds: float, trace: bool,
                 t0=win.t0, t1=win.t0 + trace_s, probes=rec, trace=red,
                 compiles_in_window=len(in_window), spans=spans,
                 window_requests=window_prog, shape=served.shape,
-                work=lambda live: dense.decode_work(served.shape, live),
+                work=lambda live: spec.family.decode_work(served.shape,
+                                                          live),
                 peaks=peaks_for(device["kind"]))
             for m in spec.per_layer:
                 v = load_reader(m["name"])(records)
@@ -618,7 +644,8 @@ def run(spec: CellSpec, seed: int, seconds: float, trace: bool,
                       -(-tr.longest(spec.mix) // 128) * 128)
             toks, rows = reference_inputs(win, picked, served.shape.vocab,
                                           pad, k)
-            gaps = logit_gap(served.shape, seed, toks, rows, fp8=control,
+            gaps = logit_gap(spec.family, served.shape, seed, toks, rows,
+                             fp8=control,
                              row_cap=k * spec.mix["output_tokens"]["hi"])
             gap = gaps["program"]
         else:

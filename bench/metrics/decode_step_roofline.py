@@ -3,9 +3,9 @@ program, per step (%).
 
 Required time is the larger of the step's needed bytes over HBM
 bandwidth and its needed FLOPs over peak, from shapes at the live
-lengths of the active rows (``bench/lib/dense.py:decode_work``). The
-device time is that of the program whose name contains ``PROGRAM``,
-averaged over its executions in the window."""
+lengths of the active rows (the family's ``decode_work``, as
+``rec.work``). The device time is that of the program whose name
+contains ``PROGRAM``, averaged over its executions in the window."""
 PROGRAM = "_decode_one"
 
 

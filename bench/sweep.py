@@ -47,7 +47,7 @@ def main(argv=None) -> int:
     # one seed for every rate: the shared prompts filled in set-up are
     # those of this seed
     scheds = [tr.schedule(spec.mix, a.seed, r, a.seconds) for r in rates]
-    served = harness.Served(spec.config, a.seed)
+    served = harness.Served(spec.config, spec.family, a.seed)
     try:
         harness.warm(served, spec, a.seed,
                      [r for s in scheds for r in s])

@@ -3,11 +3,12 @@
     JAX_PLATFORMS=cpu python -m pytest -q tests/bench_harness
 
 Traffic generation, trace reduction, the required-work functions, the
-peaks lookup, the probes, and the comparison that decides ``correct``:
-the fp8 control, a token altered where it is produced and a request
-that fails must each come out as not correct. No test here describes a
-TPU topology.
+peaks lookup, the probes, the model families loaded by name, and the
+comparison that decides ``correct``: the fp8 control, a token altered
+where it is produced and a request that fails must each come out as not
+correct. No test here describes a TPU topology.
 """
+import hashlib
 import json
 import sys
 import time
@@ -22,8 +23,10 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 sys.path.insert(0, str(ROOT / "src"))
 
-from bench.lib import dense, harness, probes, trace, traffic  # noqa: E402
+from bench.lib import common, harness, probes, trace, traffic  # noqa: E402
 from bench.lib.textproto import chat_ids  # noqa: E402
+
+dense = harness.load_family({"family": "dense"}, "the tests")
 
 MIXES = sorted((ROOT / "bench" / "traffic").glob("*.json"))
 JAX_CACHE_OPTIONS = ("jax_compilation_cache_dir",
@@ -184,13 +187,17 @@ def test_decode_roofline_is_100_when_device_time_is_the_bound():
 
 
 def test_decode_work_counts_weights_once_and_live_kv():
+    """``qwen3-4b``'s counts, to the unit, as the roofline metrics have
+    read them since the first benchmark: a family's move keeps them."""
     shape = dense.Shape(_load(ROOT / "bench" / "configs" / "qwen3-4b.json"))
-    assert dense.weight_params(shape) == pytest.approx(4.02e9, rel=0.01)
+    assert dense.weight_params(shape) == 4_022_468_096
     f1, b1 = dense.decode_work(shape, [100])
     f2, b2 = dense.decode_work(shape, [100, 100])
+    assert (f1, b1) == (8_103_526_400, 8_060_289_536)
+    assert (f2, b2) == (16_207_052_800, 8_075_642_880)
     kv_token = 36 * 2 * 8 * 128 * 2
-    assert b2 - b1 == pytest.approx(100 * kv_token + 151936 * 4)
-    assert f2 == pytest.approx(2 * f1)
+    assert b2 - b1 == 100 * kv_token + 151936 * 4
+    assert f2 == 2 * f1
 
 
 class _FakeProbes:
@@ -291,11 +298,8 @@ def test_fp8_control_fails_where_the_program_passes(seed):
     limit = _load(ROOT / "bench" / "cells" / f"{CELL}.json")[
         "logit_gap_limit"]
     sh = dense.Shape(spec)
-    params = dense.make_params_jit(sh, jnp.asarray(dense.base_key(seed)))
-    cfg = get_config(spec["registry"]).replace(
-        n_layers=sh.n_layers, d_model=sh.d, n_heads=sh.h, n_kv_heads=sh.kv,
-        head_dim=sh.dh, d_ff=sh.ff, vocab=sh.vocab, tie_embeddings=sh.tied,
-        rope_theta=sh.theta, qk_norm=sh.qk_norm)
+    params = dense.make_params_jit(sh, jnp.asarray(common.base_key(seed)))
+    cfg = dense.program_config(spec, get_config(spec["registry"]))
     toks = np.random.default_rng(0).integers(16, sh.vocab, (3, 64))
     prog = np.asarray(Model(cfg, dtype=jnp.bfloat16).forward(
         params, {"tokens": jnp.asarray(toks, jnp.int32)}))[..., :sh.vocab]
@@ -304,7 +308,7 @@ def test_fp8_control_fails_where_the_program_passes(seed):
     fp8 = dense.Reference(sh, fp8=True).logits_at(seed, toks, rows)
     for served, want in [(prog.reshape(-1, sh.vocab).argmax(-1), True),
                          (fp8.argmax(-1), False)]:
-        gap = float(dense.served_gaps(ref, served).max())
+        gap = float(common.served_gaps(ref, served).max())
         correct, checks = harness.judge(gap, len(rows), 0, limit)
         assert correct is want, checks
 
@@ -379,6 +383,129 @@ def test_a_failed_request_is_not_correct(monkeypatch):
     assert out["checks"]["failed_requests"] == {"value": 1, "limit": 0}
     assert out["checks"]["logit_gap"]["value"] <= 0.02
     assert out["correct"] is False
+
+
+# ---------------------------------------------------------------------------
+# model families, loaded by the name a configuration gives
+# ---------------------------------------------------------------------------
+
+def _params_digest(params) -> str:
+    """sha256 over every leaf's path, dtype, shape and bytes, in the
+    tree's order."""
+    import jax
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        a = np.asarray(leaf)
+        for part in (jax.tree_util.keystr(path), str(a.dtype),
+                     repr(a.shape)):
+            h.update(part.encode())
+        h.update(a.tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("name,digest", [
+    ("qwen3-4b",
+     "928d6cfd4d166a22f58d6f8b85f25487ef3fb38287af0aba0e4361174381ece8"),
+    ("yi-6b",
+     "c4f28bc2861722fdc627948e0274ea634d91f897ff93e82f072841a3a43563aa")])
+def test_dense_weights_for_a_seed_are_pinned(name, digest):
+    """The weights drawn for one seed at ``TINY``, tied with q/k norms
+    and untied without, are bit for bit those the benchmark drew before
+    its families were files, with the same leaves in the same order."""
+    import jax.numpy as jnp
+    spec = _tiny(name)
+    params = dense.make_params_jit(dense.Shape(spec),
+                                   jnp.asarray(common.base_key(2**32 + 5)))
+    assert _params_digest(params) == digest
+
+
+TOY_FAMILY = '''"""The dense family under another name, each call counted."""
+import importlib.util
+
+_spec = importlib.util.spec_from_file_location("toy_dense", {dense!r})
+_dense = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_dense)
+CALLS = []
+
+
+def _counted(name):
+    def call(*a, **kw):
+        CALLS.append(name)
+        return getattr(_dense, name)(*a, **kw)
+    return call
+
+
+Shape = _counted("Shape")
+program_config = _counted("program_config")
+make_params_jit = _counted("make_params_jit")
+Reference = _counted("Reference")
+decode_work = _counted("decode_work")
+weight_params = _counted("weight_params")
+'''
+
+
+def test_a_family_added_as_a_file_is_the_one_a_run_uses(tmp_path,
+                                                         monkeypatch):
+    """A family file in a families directory of its own, named by the
+    configuration, builds the served weights and the program's config,
+    gives the reference that decides ``correct`` and the work that the
+    per-layer metrics read (a traced run; the CPU has no device trace,
+    so its reduction and the peaks are stood in for)."""
+    fams = tmp_path / "families"
+    fams.mkdir()
+    (fams / "toy.py").write_text(TOY_FAMILY.format(
+        dense=str(harness.FAMILIES / "dense.py")))
+    monkeypatch.setattr(harness, "FAMILIES", fams)
+    monkeypatch.setattr(trace, "read_xplane", lambda f: None)
+    monkeypatch.setattr(trace, "reduce", lambda tr: {
+        "programs": {"jit__decode_one": [1e-3]}, "busy_s": 1.0,
+        "window_s": 3.0, "breakdown": {"device_ops": [], "idle_gaps": []}})
+    pk = harness.peaks_for("TPU v5 lite")
+    monkeypatch.setattr(harness, "peaks_for", lambda kind: pk)
+    b = _load(ROOT / "BENCHMARK.json")
+    work_metrics = [m for m in b["per_layer"]
+                    if m["name"] in ("decode_step_roofline", "step_mfu")]
+    cell = {"rate_per_s": 3.0, "drain_s": 30, "check_requests": 4,
+            "logit_gap_limit": 0.02, "trace_s": 3.0}
+    mix = {"shared_prompts": None,
+           "user_tokens": {"dist": "uniform", "lo": 8, "hi": 24},
+           "output_tokens": {"dist": "uniform", "lo": 4, "hi": 8}}
+    spec = harness.CellSpec.of("tiny.toy", {**_tiny("qwen3-4b"),
+                                            "family": "toy"},
+                               mix, cell, per_layer=work_metrics)
+    assert spec.family.__file__ == str(fams / "toy.py")
+    jax = harness.setup_jax()
+    out = harness.run(spec, 2**31 + 7, 3.0, True, time.perf_counter(), jax)
+    assert out["correct"] is True, out["checks"]
+    assert set(out["metrics"]) == {"decode_step_roofline", "step_mfu"}
+    assert set(spec.family.CALLS) >= {"Shape", "program_config",
+                                      "make_params_jit", "Reference",
+                                      "decode_work"}
+
+
+@pytest.mark.parametrize("family", [None, "no-such-family"],
+                         ids=["key_missing", "file_missing"])
+def test_a_configuration_without_its_family_names_file_and_families(
+        tmp_path, monkeypatch, family):
+    fams = tmp_path / "families"
+    fams.mkdir()
+    for name in ("zeta", "alpha"):
+        (fams / f"{name}.py").write_text("")
+    monkeypatch.setattr(harness, "FAMILIES", fams)
+    config = _tiny("qwen3-4b")
+    del config["family"]
+    if family:
+        config["family"] = family
+    f = tmp_path / "c.json"
+    f.write_text(json.dumps(config))
+    b = {"configs": [{"name": "c", "file": str(f)}],
+         "workloads": [{"name": "c.chat-short", "config": "c",
+                        "traffic": "chat-short", "chips": 1}],
+         "end_to_end": [], "per_layer": []}
+    with pytest.raises(ValueError) as e:
+        harness.CellSpec(b, "c.chat-short")
+    assert str(f) in str(e.value)
+    assert str(e.value).endswith("families present: alpha, zeta")
 
 
 def test_no_chip_means_no_result(capsys, monkeypatch):
